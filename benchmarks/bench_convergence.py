@@ -179,6 +179,7 @@ def measure(smoke: bool) -> dict:
 
     configs = SMOKE if smoke else FULL
     return {"devices": jax.device_count(),
+            "platform": jax.default_backend(),
             "configs": [_measure_config(c) for c in configs]}
 
 
@@ -190,6 +191,9 @@ def _run_child(smoke: bool, devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
+    # forced host devices are CPU devices: pin the platform so the child
+    # never contends for an accelerator the parent's machine may hold
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(repo, "src"), repo,
@@ -209,14 +213,12 @@ def _run_child(smoke: bool, devices: int) -> dict:
 
 def run(smoke: bool = False, devices: int = DEVICES,
         out_path: str | None = None) -> dict:
-    import jax
-
     res = _run_child(smoke, devices)
     doc = {
         "schema": BENCH_CONVERGENCE_SCHEMA,
         "generated_by": "benchmarks/bench_convergence.py",
         "smoke": smoke,
-        "platform": jax.default_backend(),
+        "platform": res["platform"],
         "devices": res["devices"],
         "configs": res["configs"],
     }

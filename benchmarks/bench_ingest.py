@@ -206,6 +206,9 @@ def _run_child(smoke: bool, devices: int, depth: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
+    # forced host devices are CPU devices: pin the platform so the child
+    # never contends for an accelerator the parent's machine may hold
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(repo, "src"), repo,

@@ -32,6 +32,7 @@ REPO = Path(__file__).resolve().parent.parent
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={M}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 import jax
 import numpy as np
@@ -75,6 +76,7 @@ def _run_for(M: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={M}"
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; leave chips alone
     proc = subprocess.run(
         [sys.executable, "-c", _SNIPPET.format(M=M)],
         env=env, capture_output=True, text=True, timeout=1800,

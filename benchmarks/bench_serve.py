@@ -232,6 +232,7 @@ def measure(smoke: bool) -> dict:
         }
 
     out["closed_loop"] = {"rows": cl_rows}
+    out["platform"] = jax.default_backend()
     return out
 
 
@@ -243,6 +244,9 @@ def _run_child(smoke: bool, devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
+    # forced host devices are CPU devices: pin the platform so the child
+    # never contends for an accelerator the parent's machine may hold
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(repo, "src"), repo,
@@ -261,8 +265,6 @@ def _run_child(smoke: bool, devices: int) -> dict:
 
 def run(smoke: bool = False, devices: int = DEVICES,
         out_path: str | None = None) -> dict:
-    import jax
-
     cfgp = SMOKE if smoke else FULL
     res = _run_child(smoke, devices)
 
@@ -270,7 +272,7 @@ def run(smoke: bool = False, devices: int = DEVICES,
         "schema": BENCH_SERVE_SCHEMA,
         "generated_by": "benchmarks/bench_serve.py",
         "smoke": smoke,
-        "platform": jax.default_backend(),
+        "platform": res["platform"],
         "config": {
             "dims": list(cfgp["dims"]),
             "nnz": cfgp["nnz"],

@@ -5,13 +5,14 @@ of local / sync / strata / strata_overlap with ``--strategy``; the default
 ``strata_overlap`` runs the Latin-hypercube epoch schedule with the factor
 shard rotations double-buffered behind compute.
 
-Simulates 8 devices on CPU (the flag below MUST precede any jax import).
+Simulates 8 devices on CPU (the flags below MUST precede any jax import).
 
     python examples/multipod_std.py [--strategy strata]
 """
 import argparse
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # simulated devices are CPU devices
 import sys                                                      # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

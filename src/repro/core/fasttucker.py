@@ -19,8 +19,7 @@ Kernel selection goes through the named-backend registry
 (``repro.kernels.dispatch``): ``FastTuckerConfig(backend="xla")`` is the
 pure-jnp reference path, ``"pallas"`` / ``"pallas_interpret"`` route the
 ENTIRE hot path — contraction, Eq.13/17 gradients, and the factor-row
-scatter — through the fused Pallas kernels, identical numerics.  The old
-``use_kernel: bool`` switch is kept as a deprecated shim.
+scatter — through the fused Pallas kernels, identical numerics.
 
 Phase-split step (cuFasterTucker's invariant-intermediate caching): the
 update decomposes into a *factor phase* (Eq. 13, B^(n) frozen) and a
@@ -62,7 +61,6 @@ tolerance-equal to that same reference.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import NamedTuple, Sequence
 
@@ -75,23 +73,6 @@ from .sampling import (
     SortedBatchLayout, sample_batch_arrays, sorted_batch_layout,
 )
 from .sptensor import SparseTensor
-
-
-def _resolve_backend(
-    backend: str | None, use_kernel: bool | None, caller: str
-) -> str:
-    """Map the deprecated ``use_kernel`` flag onto a backend name."""
-    if use_kernel is not None:
-        warnings.warn(
-            f"{caller}(use_kernel=...) is deprecated; pass "
-            "backend='xla'/'pallas'/'pallas_interpret' instead",
-            DeprecationWarning, stacklevel=3,
-        )
-        if backend is None:
-            backend = (
-                dispatch.default_pallas_backend() if use_kernel else "xla"
-            )
-    return dispatch.resolve_backend_name(backend)
 
 
 class FastTuckerParams(NamedTuple):
@@ -131,18 +112,8 @@ class FastTuckerConfig:
     warm_step_offset: int = 0       # start the decaying LR schedule here
                                     # (warm init replaces the cold ramp-in;
                                     # raise if SGD diverges from a warm start)
-    use_kernel: dataclasses.InitVar[bool | None] = None  # DEPRECATED shim
 
-    def __post_init__(self, use_kernel: bool | None) -> None:
-        if use_kernel is not None:
-            warnings.warn(
-                "FastTuckerConfig(use_kernel=...) is deprecated; use "
-                "backend='xla'/'pallas'/'pallas_interpret'",
-                DeprecationWarning, stacklevel=2,
-            )
-            if use_kernel and self.backend == "xla":
-                object.__setattr__(
-                    self, "backend", dispatch.default_pallas_backend())
+    def __post_init__(self) -> None:
         if self.dtype not in ("float32", "bfloat16"):
             raise ValueError(
                 f"dtype must be 'float32' or 'bfloat16', got {self.dtype!r}")
@@ -350,7 +321,6 @@ def batch_gradients(
     lambda_a: float,
     lambda_b: float,
     mask: jax.Array | None = None,
-    use_kernel: bool | None = None,
     row_mean: bool = False,
     backend: str | None = None,
     accum_dtype=None,
@@ -365,12 +335,11 @@ def batch_gradients(
     The whole computation dispatches to ``backend`` (see
     ``repro.kernels.dispatch``): on the Pallas flavors the contraction AND
     both gradient stages run inside a single ``pallas_call``
-    (``repro.kernels.kruskal_grad``). ``use_kernel`` is a deprecated alias
-    for ``backend=<default pallas flavor>``.  See
+    (``repro.kernels.kruskal_grad``).  See
     ``factor_phase_gradients`` / ``core_phase_gradients`` for the
     phase-split flavor with cached intermediates.
     """
-    backend = _resolve_backend(backend, use_kernel, "batch_gradients")
+    backend = dispatch.resolve_backend_name(backend)
     rows = gather_rows(params.factors, idx, layout)
     kg = dispatch.get_backend(backend).kruskal_grad(
         rows, params.core_factors, val,

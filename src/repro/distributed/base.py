@@ -193,8 +193,7 @@ def resolve_strategy_name(name: str | None = None,
                           mode: str | None = None) -> str:
     """explicit ``name`` > deprecated ``mode`` > $REPRO_DIST_STRATEGY > local.
 
-    ``mode`` is the pre-registry ``--mode`` flag; passing it warns (same
-    treatment as the kernel registry gave ``--use-kernel``).
+    ``mode`` is the pre-registry ``--mode`` flag; passing it warns.
     """
     if name:
         return name

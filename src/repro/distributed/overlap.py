@@ -64,7 +64,7 @@ class OverlapPlan(StrataRunPlan):
 
 
 def _build_chunk_specializer(plan: OverlapPlan):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     cfg, layout, axis = plan.cfg, plan.layout, plan.axis
     M, N = layout.num_workers, cfg.order
@@ -109,7 +109,7 @@ def _build_chunk_specializer(plan: OverlapPlan):
             mesh=plan.mesh,
             in_specs=(spec, P(axis), P(axis), P(axis)),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=step_donation())
 

@@ -199,7 +199,7 @@ def make_strata_step(cfg: FastTuckerConfig, mesh: Mesh, plan: StrataLayout,
     M = plan.num_workers
     N = cfg.order
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     # The stratum is host-chosen per step, so specialize the compiled step
     # per digit tuple: rotations become STATIC ppermutes (no lax.switch over
@@ -237,7 +237,7 @@ def make_strata_step(cfg: FastTuckerConfig, mesh: Mesh, plan: StrataLayout,
                 tuple(P(axis, None) for _ in range(N)),
                 tuple(P() for _ in range(N)),
             ),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded)
 
@@ -330,7 +330,7 @@ def _init_strata_state(plan, state: TrainState, key) -> DistState:
 
 
 def _build_strata_specializer(plan: StrataRunPlan):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     cfg, layout, axis = plan.cfg, plan.layout, plan.axis
     M, N = layout.num_workers, cfg.order
@@ -365,7 +365,7 @@ def _build_strata_specializer(plan: StrataRunPlan):
             mesh=plan.mesh,
             in_specs=(spec, P(axis), P(axis), P(axis)),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=step_donation())
 

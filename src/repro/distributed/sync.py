@@ -83,7 +83,7 @@ def make_sync_step(cfg: FastTuckerConfig, mesh: Mesh, axis: str = "data",
     through the registry (its EF residuals are properly device-sharded
     instead of replicated-with-divergence).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def local_step(params, step_no, key, idx_shard, val_shard, ef):
         # shard_map blocks keep a size-1 leading dim — drop it
@@ -97,7 +97,7 @@ def make_sync_step(cfg: FastTuckerConfig, mesh: Mesh, axis: str = "data",
         mesh=mesh,
         in_specs=(P(), P(), P(), P(axis), P(axis), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -121,7 +121,7 @@ class SyncPlan:
 
 
 def _build_jitted(plan: SyncPlan):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     cfg, axis = plan.cfg, plan.axis
 
@@ -149,7 +149,7 @@ def _build_jitted(plan: SyncPlan):
         mesh=plan.mesh,
         in_specs=(state_spec, P(plan.axis), P(plan.axis)),
         out_specs=state_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=step_donation())
 

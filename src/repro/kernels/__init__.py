@@ -13,15 +13,15 @@ Layout:
                         dense sweep, batch order free
     segment_reduce.py   segmented-reduce scatter for MODE-SORTED batches
                         (``core.sampling.sorted_batch_layout`` /
-                        ``FastTuckerConfig(sorted_batches=True)``): walks
-                        contiguous batch tiles into the revisited row
-                        block — O(B) adds, zero MXU work, bitwise equal
-                        to the jnp reference (Pallas)
+                        ``FastTuckerConfig(sorted_batches=True)``): row
+                        tiles of the output, each fed its contiguous run
+                        of sorted entries — O(B) adds, zero MXU work,
+                        bitwise equal to the jnp reference (Pallas)
     tucker_matmul.py    Tucker-2 factorized dense layer (Pallas)
     flash_attention.py  flash attention for the LM workload (Pallas)
+    tiling.py           shared TPU tiling rules (lane-dense per-sample
+                        rows, VMEM-budgeted batch tiles)
     ref.py              pure-jnp oracles for every kernel (test ground truth)
-    ops.py              legacy wrappers (pre-registry API; delegates to
-                        dispatch's default Pallas flavor)
 
 Call sites select a backend by name — ``FastTuckerConfig(backend=...)``,
 ``--backend`` on the launch CLIs — and everything downstream routes through

@@ -1,12 +1,15 @@
 """Named kernel-backend registry — one dispatch point for every hot-loop op.
 
-Replaces the old module-global ``repro.kernels.ops.INTERPRET`` flag and the
-``use_kernel: bool`` switch with named backends:
+Named backends:
 
     ``"xla"``              pure-jnp reference path (default; runs anywhere)
     ``"pallas"``           Pallas kernels compiled via Mosaic (TPU)
     ``"pallas_interpret"`` Pallas kernels in interpret mode (CPU-testable,
                            bit-for-bit the same kernel bodies as ``"pallas"``)
+
+Interpret mode is reached only by naming ``"pallas_interpret"``: the
+kernel entry points have no ``interpret`` default, and nothing maps a
+generic "use the kernels" request onto the interpreter.
 
 Resolution order for ``get_backend(name)``:
 
@@ -47,7 +50,6 @@ import jax.numpy as jnp
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 DEFAULT_BACKEND = "xla"
-PALLAS_BACKENDS = ("pallas", "pallas_interpret")
 
 
 class KruskalGrads(NamedTuple):
@@ -238,14 +240,15 @@ def _stack_padded_factors(core_factors: Sequence[jax.Array]) -> jax.Array:
 
 
 class PallasBackend:
-    """Pallas kernels; ``interpret=True`` runs the same bodies on CPU."""
+    """Pallas kernels; ``interpret=True`` runs the same bodies on CPU.
 
-    def __init__(self, name: str, interpret: bool,
-                 block_b: int = 512, block_i: int = 256):
+    Tile sizes are the kernels' own (picked from the VMEM budget), so the
+    backend carries only its name and whether it interprets.
+    """
+
+    def __init__(self, name: str, interpret: bool):
         self.name = name
         self.interpret = interpret
-        self.block_b = block_b
-        self.block_i = block_i
 
     mode_dot = staticmethod(_mode_dot)
 
@@ -259,7 +262,7 @@ class PallasBackend:
 
         a = _stack_padded_rows(rows)
         b = _stack_padded_factors(core_factors)
-        return kc(a, b, block_b=self.block_b, interpret=self.interpret,
+        return kc(a, b, interpret=self.interpret,
                   accum_dtype=str(resolve_accum_dtype(accum_dtype)))
 
     def kruskal_grad(
@@ -309,7 +312,7 @@ class PallasBackend:
         outs = kg(
             a, b, val_in.astype(acc_dt), mask_f, scal, c_stacked,
             row_modes=row_modes, want_core=want_core, emit_c=emit_c,
-            block_b=self.block_b, interpret=self.interpret,
+            interpret=self.interpret,
             accum_dtype=str(jnp.dtype(acc_dt)),
         )
         if row_modes is None:
@@ -332,19 +335,14 @@ class PallasBackend:
     ) -> jax.Array:
         from .scatter_accum import scatter_accum as sa
 
-        return sa(
-            grads, idx, num_rows,
-            block_i=self.block_i, block_b=self.block_b,
-            interpret=self.interpret,
-        )
+        return sa(grads, idx, num_rows, interpret=self.interpret)
 
     def segment_reduce(
         self, grads: jax.Array, idx: jax.Array, num_rows: int
     ) -> jax.Array:
         from .segment_reduce import segment_reduce as sr
 
-        return sr(grads, idx, num_rows, block_b=self.block_b,
-                  interpret=self.interpret)
+        return sr(grads, idx, num_rows, interpret=self.interpret)
 
     def tucker_matmul(self, x, u1, g, u2) -> jax.Array:
         from .tucker_matmul import tucker_matmul as tm
@@ -386,20 +384,6 @@ def get_backend(name: str | None = None):
             f"unknown kernel backend {resolved!r}; "
             f"available: {available_backends()}"
         ) from None
-
-
-def default_pallas_backend() -> str:
-    """The Pallas flavor legacy ``use_kernel=True`` call sites map to.
-
-    Honors ``$REPRO_KERNEL_BACKEND`` when it names a Pallas flavor and the
-    legacy ``$REPRO_PALLAS_COMPILE=1`` escape hatch (compile via Mosaic).
-    """
-    env = os.environ.get(ENV_VAR)
-    if env in PALLAS_BACKENDS:
-        return env
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return "pallas"
-    return "pallas_interpret"
 
 
 # ---------------------------------------------------------------------------
@@ -485,7 +469,6 @@ __all__ = [
     "ENV_VAR",
     "DEFAULT_BACKEND",
     "DEFAULT_ACCUM",
-    "PALLAS_BACKENDS",
     "KruskalGrads",
     "resolve_accum_dtype",
     "XlaBackend",
@@ -494,7 +477,6 @@ __all__ = [
     "available_backends",
     "resolve_backend_name",
     "get_backend",
-    "default_pallas_backend",
     "kruskal_predict",
     "count_pallas_calls",
 ]

@@ -82,7 +82,7 @@ def flash_attention_fwd(
     causal: bool = True,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     BH, Sq, D = q.shape
     Sk = k.shape[1]

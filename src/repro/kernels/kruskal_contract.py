@@ -14,9 +14,8 @@ nothing: they add 0 to every dot product). The small Kruskal factors
 ``B^(n)`` (N·J·R ≤ 10·32·32 floats) are fully VMEM-resident in every grid
 step — the TPU analogue of the paper keeping B^(n) in shared memory.
 
-Grid: 1-D over batch tiles. VMEM per step ≈ N·BT·J + N·J·R + N·BT·R floats;
-for N=4, BT=512, J=R=32 that is ~0.6 MB — far under the ~16 MB VMEM budget,
-so BT can grow to 4096 (see benchmarks/bench_kernel_blocks.py for the sweep).
+Grid: 1-D over batch tiles, sized from the VMEM budget after lane padding
+(``kernels.tiling``); ``pred`` is written as a lane-dense ``(1, B)`` row.
 """
 from __future__ import annotations
 
@@ -26,10 +25,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import tiling
+
 
 def _kernel(a_ref, b_ref, pred_ref, pexc_ref, *, n_modes: int,
             accum_dtype: str):
-    # a_ref: (N, BT, J); b_ref: (N, J, R); pred_ref: (BT,); pexc_ref: (N, BT, R)
+    # a_ref: (N, BT, J); b_ref: (N, J, R); pred_ref: (1, BT);
+    # pexc_ref: (N, BT, R)
     acc_dt = jnp.dtype(accum_dtype)
     cs = []
     for n in range(n_modes):  # static unroll over modes (N ≤ 10)
@@ -53,7 +55,8 @@ def _kernel(a_ref, b_ref, pred_ref, pexc_ref, *, n_modes: int,
     for n in reversed(range(n_modes)):
         suffix[n] = acc
         acc = acc * cs[n]
-    pred_ref[...] = jnp.sum(full, axis=-1).astype(pred_ref.dtype)
+    pred = jnp.sum(full, axis=-1, keepdims=True)
+    pred_ref[...] = tiling.col_to_row(pred).astype(pred_ref.dtype)
     for n in range(n_modes):
         pexc_ref[n] = (prefix[n] * suffix[n]).astype(pexc_ref.dtype)
 
@@ -64,11 +67,11 @@ def kruskal_contract(
     a_rows: jax.Array,  # (N, B, J)
     b_fac: jax.Array,   # (N, J, R)
     *,
-    block_b: int = 512,
-    interpret: bool = True,
+    block_b: int | None = None,
+    interpret: bool,
     accum_dtype: str = "float32",
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (pred (B,), pexc (N, B, R)). interpret=True on CPU.
+    """Returns (pred (B,), pexc (N, B, R)).
 
     Results come back in ``accum_dtype`` even for bf16 storage inputs —
     the in-kernel dots already accumulate at that precision; don't round
@@ -77,11 +80,14 @@ def kruskal_contract(
     N, B, J = a_rows.shape
     R = b_fac.shape[-1]
     acc_dt = jnp.dtype(accum_dtype)
-    bt = min(block_b, B)
-    if B % bt:
-        pad = bt - B % bt
-        a_rows = jnp.pad(a_rows, ((0, 0), (0, pad), (0, 0)))
-    Bp = a_rows.shape[1]
+    per_sample = (
+        2 * N * tiling.lane_bytes(J, a_rows.dtype.itemsize)
+        + 2 * N * tiling.lane_bytes(R, acc_dt.itemsize)       # pexc out
+        + (3 * N + 1) * tiling.lane_bytes(R, acc_dt.itemsize)
+    )
+    bt, Bp = tiling.batch_tile(B, per_sample, block_b)
+    if Bp != B:
+        a_rows = jnp.pad(a_rows, ((0, 0), (0, Bp - B), (0, 0)))
     grid = (Bp // bt,)
     pred, pexc = pl.pallas_call(
         functools.partial(_kernel, n_modes=N, accum_dtype=accum_dtype),
@@ -91,13 +97,14 @@ def kruskal_contract(
             pl.BlockSpec((N, J, R), lambda i: (0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bt,), lambda i: (i,)),
+            pl.BlockSpec((1, bt), lambda i: (0, i)),
             pl.BlockSpec((N, bt, R), lambda i: (0, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), acc_dt),
+            jax.ShapeDtypeStruct((1, Bp), acc_dt),
             jax.ShapeDtypeStruct((N, Bp, R), acc_dt),
         ],
+        compiler_params=tiling.compiler_params("parallel"),
         interpret=interpret,
     )(a_rows, b_fac)
-    return pred[:B], pexc[:, :B]
+    return pred[0, :B], pexc[:, :B]

@@ -48,9 +48,12 @@ Zero padding is exact end to end: padded J columns produce zero dot
 products and zero gradient columns; padded batch rows carry mask 0 and
 therefore contribute nothing to the core accumulator.
 
-Grid: 1-D over batch tiles. VMEM per step ≈ 2·N·BT·J + 2·N·J·R +
-2·N·BT·R + 3·BT floats — for N=4, BT=512, J=R=32 about 1.4 MB, far under
-the ~16 MB budget.
+Layout: the per-sample vectors (val, mask, pred, err) are lane-dense
+``(1, B)`` rows and the five scalars sit in SMEM (``kernels.tiling``), so
+the kernel lowers for TPU whatever tiling XLA gives a 1-D array.  Grid:
+1-D over batch tiles; the tile is the largest multiple of 128 whose
+lane-padded buffers fit the VMEM budget (about 1.4k samples at N=3,
+J=R=32), and any batch is zero-padded up to whole tiles.
 """
 from __future__ import annotations
 
@@ -60,6 +63,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import tiling
 
 # layout of the scalar vector input; PRED_COEF generalizes the residual to
 # err = (pred_coef·pred − val)·mask — 1 for training (err = pred − x), 0 for
@@ -82,9 +88,9 @@ class KernelOuts(NamedTuple):
 
 def _kernel(*refs, n_modes: int, row_modes: tuple, want_core: bool,
             emit_c: bool, consume_c: bool, accum_dtype: str):
-    # ins:  scal (5,); a (N, BT, J); b (N, J, R); val (BT,); mask (BT,);
-    #       [c_in (N, BT, R) when consume_c]
-    # outs: pred (BT,); err (BT,); [rg (len(row_modes), BT, J)];
+    # ins:  scal (5,) SMEM; a (N, BT, J); b (N, J, R); val (1, BT);
+    #       mask (1, BT); [c_in (N, BT, R) when consume_c]
+    # outs: pred (1, BT); err (1, BT); [rg (len(row_modes), BT, J)];
     #       [cg (N, J, R) — revisited across the grid]; [c_out (N, BT, R)]
     acc_dt = jnp.dtype(accum_dtype)
     it = iter(refs)
@@ -122,18 +128,18 @@ def _kernel(*refs, n_modes: int, row_modes: tuple, want_core: bool,
         suffix[n] = acc
         acc = acc * cs[n]
 
-    pred = jnp.sum(full, axis=-1)                       # (BT,) accum
-    mask = mask_ref[...].astype(pred.dtype)
-    err = (scal_ref[SCAL_PRED_COEF] * pred
-           - val_ref[...].astype(pred.dtype)) * mask
-    pred_ref[...] = pred.astype(pred_ref.dtype)
-    err_ref[...] = err.astype(err_ref.dtype)
+    pred = jnp.sum(full, axis=-1, keepdims=True)        # (BT, 1) accum
+    mask = tiling.row_to_col(mask_ref[...].astype(pred.dtype))
+    val = tiling.row_to_col(val_ref[...].astype(pred.dtype))
+    err = (scal_ref[SCAL_PRED_COEF] * pred - val) * mask
+    pred_ref[...] = tiling.col_to_row(pred).astype(pred_ref.dtype)
+    err_ref[...] = tiling.col_to_row(err).astype(err_ref.dtype)
 
     inv_row = scal_ref[SCAL_INV_ROW]
     inv_core = scal_ref[SCAL_INV_CORE]
     lam_a = scal_ref[SCAL_LAM_A]
     lam_b = scal_ref[SCAL_LAM_B]
-    w_row = err * inv_row                               # (BT,)
+    w_row = err * inv_row                               # (BT, 1)
     w_core = err * inv_core
 
     if want_core:
@@ -149,15 +155,15 @@ def _kernel(*refs, n_modes: int, row_modes: tuple, want_core: bool,
             preferred_element_type=acc_dt,
         )                                               # (BT, J)
         rg_ref[j] = (
-            w_row[:, None] * d_n
-            + (lam_a * inv_row) * mask[:, None] * a_ref[n]
+            w_row * d_n
+            + (lam_a * inv_row) * mask * a_ref[n].astype(acc_dt)
         ).astype(rg_ref.dtype)
     if want_core:
         for n in range(n_modes):
             pexc_n = prefix[n] * suffix[n]
             # Eq. 17 partial: aᵀ (err ⊙ pexc), accumulated across batch tiles
             cg_ref[n] += jax.lax.dot_general(
-                a_ref[n].astype(acc_dt), w_core[:, None] * pexc_n,
+                a_ref[n].astype(acc_dt), w_core * pexc_n,
                 (((0,), (0,)), ((), ())),
                 preferred_element_type=acc_dt,
             ).astype(cg_ref.dtype)
@@ -177,8 +183,8 @@ def kruskal_grad(
     row_modes: tuple[int, ...] | None = None,  # None = all; () = none
     want_core: bool = True,
     emit_c: bool = False,
-    block_b: int = 512,
-    interpret: bool = True,
+    block_b: int | None = None,
+    interpret: bool,
     accum_dtype: str = "float32",
 ) -> KernelOuts:
     """Fused contraction + Eq.13/17 gradients in a single ``pallas_call``.
@@ -187,7 +193,9 @@ def kruskal_grad(
     phase-split step uses ``emit_c`` (factor phase: cache the mode
     products) and ``c=``/``row_modes``/``want_core`` (consume the cache,
     compute only the gradients this phase needs).  ``core_grads`` already
-    includes the λ_b·B regularizer term.
+    includes the λ_b·B regularizer term.  ``block_b`` caps the batch tile
+    (default: what fits the VMEM budget); ``interpret`` has no default —
+    the backend states whether the kernel is compiled or interpreted.
     """
     N, B, J = a_rows.shape
     R = b_fac.shape[-1]
@@ -195,36 +203,42 @@ def kruskal_grad(
     if row_modes is None:
         row_modes = tuple(range(N))
     nr = len(row_modes)
-    bt = min(block_b, B)
-    if B % bt:
-        pad = bt - B % bt
+    acc_b = acc_dt.itemsize
+    per_sample = (
+        2 * N * tiling.lane_bytes(J, a_rows.dtype.itemsize)   # a, 2 buffers
+        + 2 * nr * tiling.lane_bytes(J, acc_b)                # row grads
+        + 2 * N * tiling.lane_bytes(R, acc_b) * ((c is not None) + emit_c)
+        + (3 * N + 4) * tiling.lane_bytes(R, acc_b)           # c/chains/pexc
+        + N * tiling.lane_bytes(J, acc_b)                     # a in accum
+    )
+    bt, Bp = tiling.batch_tile(B, per_sample, block_b)
+    if Bp != B:
+        pad = Bp - B
         a_rows = jnp.pad(a_rows, ((0, 0), (0, pad), (0, 0)))
         val = jnp.pad(val, (0, pad))
         mask = jnp.pad(mask, (0, pad))  # zeros: no core/err contribution
         if c is not None:
             c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
-    Bp = a_rows.shape[1]
     grid = (Bp // bt,)
+    row_spec = pl.BlockSpec((1, bt), lambda i: (0, i))
 
     in_specs = [
-        pl.BlockSpec((NUM_SCALARS,), lambda i: (0,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((N, bt, J), lambda i: (0, i, 0)),
         pl.BlockSpec((N, J, R), lambda i: (0, 0, 0)),
-        pl.BlockSpec((bt,), lambda i: (i,)),
-        pl.BlockSpec((bt,), lambda i: (i,)),
+        row_spec,
+        row_spec,
     ]
-    operands = [scal, a_rows, b_fac, val, mask]
+    operands = [scal.astype(jnp.float32), a_rows, b_fac,
+                val.reshape(1, Bp), mask.reshape(1, Bp)]
     if c is not None:
         in_specs.append(pl.BlockSpec((N, bt, R), lambda i: (0, i, 0)))
         operands.append(c)
 
-    out_specs = [
-        pl.BlockSpec((bt,), lambda i: (i,)),
-        pl.BlockSpec((bt,), lambda i: (i,)),
-    ]
+    out_specs = [row_spec, row_spec]
     out_shape = [
-        jax.ShapeDtypeStruct((Bp,), acc_dt),
-        jax.ShapeDtypeStruct((Bp,), acc_dt),
+        jax.ShapeDtypeStruct((1, Bp), acc_dt),
+        jax.ShapeDtypeStruct((1, Bp), acc_dt),
     ]
     if nr:
         out_specs.append(pl.BlockSpec((nr, bt, J), lambda i: (0, i, 0)))
@@ -245,11 +259,13 @@ def kruskal_grad(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        # the core-gradient block is revisited by every step
+        compiler_params=tiling.compiler_params("arbitrary"),
         interpret=interpret,
     )(*operands)
 
     it = iter(outs)
-    pred, err = next(it)[:B], next(it)[:B]
+    pred, err = next(it)[0, :B], next(it)[0, :B]
     rg = next(it)[:, :B] if nr else None
     cg = next(it) if want_core else None
     c_out = next(it)[:, :B] if emit_c else None

@@ -12,7 +12,10 @@ the revisiting-output trick: the output block index depends only on the row
 tile, so Pallas keeps the block resident in VMEM across the inner batch-tile
 grid dimension.
 
-Grid: (rows/IT, B/BT), output revisited along the second axis.
+Grid: (rows/IT, B/BT), output revisited along the second axis.  The row
+ids arrive as a lane-dense ``(1, B)`` row, which is exactly the
+orientation the ``(IT, BT)`` one-hot compare broadcasts along, so no
+relayout is needed (``kernels.tiling``).
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import tiling
 
 
 def _kernel(idx_ref, g_ref, out_ref, *, block_i: int):
@@ -31,12 +36,11 @@ def _kernel(idx_ref, g_ref, out_ref, *, block_i: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     i0 = pl.program_id(0) * block_i
-    idx = idx_ref[...]                      # (BT,)
+    local = idx_ref[...] - i0               # (1, BT)
     g = g_ref[...]                          # (BT, J)
-    local = idx - i0                        # (BT,)
-    bt = idx.shape[0]
+    bt = g.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_i, bt), 0)
-    onehot = (rows == local[None, :]).astype(g.dtype)   # (IT, BT)
+    onehot = (rows == local).astype(g.dtype)            # (IT, BT)
     out_ref[...] += jax.lax.dot_general(
         onehot, g, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -53,28 +57,34 @@ def scatter_accum(
     *,
     block_i: int = 256,
     block_b: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Segment-sum scatter -> (num_rows, J). Exact (duplicates summed)."""
+    """Segment-sum scatter -> (num_rows, J). Exact (duplicates summed).
+
+    ``block_b`` caps the batch tile: every batch tile is one more f32
+    accumulation into the revisited row block, so the cap fixes the
+    association order of duplicate rows independently of the VMEM budget.
+    """
     B, J = grads.shape
-    bt = min(block_b, B)
-    if B % bt:
-        pad = bt - B % bt
-        grads = jnp.pad(grads, ((0, pad), (0, 0)))
-        idx = jnp.pad(idx, (0, pad), constant_values=-1)  # no row matches -1
-    Bp = grads.shape[0]
-    it = min(block_i, num_rows)
-    rows_p = -(-num_rows // it) * it
+    it = min(block_i, tiling.round_up(num_rows, tiling.SUBLANES))
+    per_sample = (2 * tiling.lane_bytes(J, grads.dtype.itemsize)
+                  + 2 * it * 4)                      # ids + one-hot column
+    bt, Bp = tiling.batch_tile(B, per_sample, block_b)
+    if Bp != B:
+        grads = jnp.pad(grads, ((0, Bp - B), (0, 0)))
+        idx = jnp.pad(idx, (0, Bp - B), constant_values=-1)  # matches no row
+    rows_p = tiling.round_up(num_rows, it)
     grid = (rows_p // it, Bp // bt)
     out = pl.pallas_call(
         functools.partial(_kernel, block_i=it),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bt,), lambda i, b: (b,)),
+            pl.BlockSpec((1, bt), lambda i, b: (0, b)),
             pl.BlockSpec((bt, J), lambda i, b: (b, 0)),
         ],
         out_specs=pl.BlockSpec((it, J), lambda i, b: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, J), grads.dtype),
+        compiler_params=tiling.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(idx.astype(jnp.int32), grads)
+    )(idx.astype(jnp.int32).reshape(1, Bp), grads)
     return out[:num_rows]

@@ -61,7 +61,7 @@ def tucker_matmul(
     block_m: int = 256,
     block_n: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     M, K = x.shape
     R1 = u1.shape[1]

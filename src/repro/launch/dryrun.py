@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (device count locks at
-# first init). Placeholder host devices exist ONLY for this dry-run.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import (device count locks at
+# first init). Placeholder host devices exist ONLY for this dry-run, and
+# they are CPU devices: the dry-run never touches an accelerator.
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -270,6 +272,8 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/dryrun")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
 
     archs = ARCH_IDS if args.arch == "all" else args.arch.split(",")
     shapes = list(SHAPES) if args.shape == "all" else args.shape.split(",")

@@ -102,6 +102,8 @@ def main() -> None:
     ap.add_argument("--max-attempts", type=int, default=3,
                     help="per-cycle retry budget before the breaker trips")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     from repro.kernels import dispatch

@@ -35,6 +35,8 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     cfg = get_config(args.arch, reduced=args.reduced)

@@ -118,6 +118,8 @@ def main() -> None:
                          "answers still flow past the budget)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     from repro.kernels import dispatch

@@ -145,9 +145,6 @@ def main() -> None:
                     choices=["auto", "on", "off"],
                     help="donate the DistState buffers into the compiled "
                          "step (auto: off-CPU only)")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="DEPRECATED: alias for --backend "
-                         "pallas/pallas_interpret")
     ap.add_argument("--out-of-core", action="store_true",
                     help="feed the strata strategies from a chunk-sharded "
                          "NonzeroStore through the host→device stratum "
@@ -197,6 +194,8 @@ def main() -> None:
                          "config/strategy — the manager keeps only the "
                          "highest-numbered steps)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     # the strategies read the donation policy when they BUILD their jitted
@@ -207,11 +206,7 @@ def main() -> None:
     os.environ[DONATE_ENV_VAR] = args.donate
 
     from repro.kernels import dispatch
-    backend = args.backend
-    if backend is None and args.use_kernel:
-        backend = dispatch.default_pallas_backend()
-        log.warning("--use-kernel is deprecated; use --backend %s", backend)
-    backend = dispatch.resolve_backend_name(backend)
+    backend = dispatch.resolve_backend_name(args.backend)
     dispatch.get_backend(backend)  # fail fast on typos, before data gen
 
     # fail fast on strategy typos too (--mode maps through with a warning)

@@ -69,6 +69,8 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     cfg = get_config(args.arch, reduced=args.reduced)

@@ -171,7 +171,6 @@ def init_tucker_linear(key, d_in: int, d_out: int, rank: int,
 
 
 def tucker_linear(params: dict, x: jax.Array,
-                  use_kernel: bool | None = None,
                   backend: str | None = None) -> jax.Array:
     """Tucker-2 factorized dense layer, routed through the kernel registry.
 
@@ -179,21 +178,10 @@ def tucker_linear(params: dict, x: jax.Array,
     ``$REPRO_KERNEL_BACKEND``: the Pallas ``tucker_matmul`` has no custom
     VJP, so an env-var set for the FastTucker workload must not silently
     reroute (and break ``jax.grad`` of) the LM forward.  Pallas flavors
-    are explicit opt-in here.  ``use_kernel`` is a deprecated alias.
+    are explicit opt-in here.
     """
     from repro.kernels import dispatch
 
-    if use_kernel is not None:
-        import warnings
-
-        warnings.warn(
-            "tucker_linear(use_kernel=...) is deprecated; pass "
-            "backend='xla'/'pallas'/'pallas_interpret' instead",
-            DeprecationWarning, stacklevel=2,
-        )
-        if backend is None:
-            backend = (
-                dispatch.default_pallas_backend() if use_kernel else "xla")
     bk = dispatch.get_backend(backend or "xla")
     shape = x.shape
     y = bk.tucker_matmul(

@@ -115,7 +115,7 @@ def moe_ffn_sharded(params: dict, cfg, x: jax.Array, mesh) -> jax.Array:
       then ONE psum over `model` combines expert contributions — the only
       collective, of activation size.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -197,7 +197,7 @@ def moe_ffn_sharded(params: dict, cfg, x: jax.Array, mesh) -> jax.Array:
             P(bspec, None, None),
         ),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params["router"], params["wi"], params["wg"], params["wo"],
               shared, x)

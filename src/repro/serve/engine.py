@@ -394,7 +394,7 @@ class TuckerServer:
     # -- row-sharded query programs (shard-local + one small collective) ------
 
     def _build_row_predict(self, donate: bool):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N = self.mesh, self.order
         block_rows, eyes, backend = self._block_rows, self._eyes, self.backend
@@ -421,7 +421,7 @@ class TuckerServer:
             local_fn, mesh=mesh,
             in_specs=(tuple(P("data", None) for _ in range(N)), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         # signature-compatible with the unsharded/batch predict (eyes are
         # already closed over): predict() calls every mode identically
@@ -440,7 +440,7 @@ class TuckerServer:
         top-k — the flash-decode shard-merge idiom.  The only collectives
         are one (B, R) psum (coefficient-row gather) and one O(M·k·B)
         all-gather; GSPMD's layout-chosen alternative gathers O(rows)."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N = self.mesh, self.order
         block_rows = self._block_rows
@@ -487,7 +487,7 @@ class TuckerServer:
                 in_specs=(tuple(P("data", None) for _ in range(N)),
                           tuple(P() for _ in range(N)), P()),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(tables, colsums, ids)
 
@@ -500,7 +500,7 @@ class TuckerServer:
         block concatenation.  Only the smaller free modes' tables are
         all-gathered — the collective payload is the (unavoidable) result
         plus the small tables, never the big one."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N = self.mesh, self.order
         block_rows = self._block_rows
@@ -534,7 +534,7 @@ class TuckerServer:
                 local_fn, mesh=mesh,
                 in_specs=(tuple(P("data", None) for _ in range(N)), P()),
                 out_specs=P(*out_axes),
-                check_rep=False,
+                check_vma=False,
             )
             out = sharded(tables, ids)
             # trim n1's row padding (pad rows are zeros, but the caller
@@ -546,7 +546,7 @@ class TuckerServer:
     # -- batch-sharded query programs (replicated tables, split batches) ------
 
     def _build_batch_predict(self, donate: bool):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N, backend = self.mesh, self.order, self.backend
 
@@ -562,12 +562,12 @@ class TuckerServer:
                       tuple(P(None, None) for _ in range(N)),
                       P("data", None)),
             out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=(2,) if donate else ())
 
     def _build_batch_top_k(self, donate: bool):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N = self.mesh, self.order
 
@@ -584,14 +584,14 @@ class TuckerServer:
                 in_specs=(tuple(P(None, None) for _ in range(N)),
                           tuple(P() for _ in range(N)), P("data")),
                 out_specs=(P("data"), P("data")),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(tables, colsums, ids)
 
         return fn
 
     def _build_batch_reconstruct(self, donate: bool):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh, N = self.mesh, self.order
 
@@ -605,7 +605,7 @@ class TuckerServer:
                 local_fn, mesh=mesh,
                 in_specs=(tuple(P(None, None) for _ in range(N)), P("data")),
                 out_specs=P("data", *([None] * (N - 1))),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(tables, ids)
 

@@ -244,14 +244,3 @@ def test_register_custom_backend():
             dispatch.register_backend(Fake())
     finally:
         dispatch._REGISTRY.pop("fake_test_backend", None)
-
-
-def test_use_kernel_deprecation_shim():
-    with pytest.warns(DeprecationWarning):
-        cfg = FastTuckerConfig(dims=(8, 8, 8), ranks=(2, 2, 2), core_rank=2,
-                               use_kernel=True)
-    assert cfg.backend in dispatch.PALLAS_BACKENDS
-    with pytest.warns(DeprecationWarning):
-        cfg2 = FastTuckerConfig(dims=(8, 8, 8), ranks=(2, 2, 2), core_rank=2,
-                                use_kernel=False)
-    assert cfg2.backend == "xla"

@@ -194,7 +194,9 @@ def test_sharded_moe_matches_dense_dispatch():
         from repro.models.layers import unbox
         from repro.models.moe import init_moe
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         for arch in ("qwen3_moe_30b_a3b", "deepseek_v2_lite_16b"):
             cfg = dataclasses.replace(get_config(arch, reduced=True),
                                       capacity_factor=8.0)

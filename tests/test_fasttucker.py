@@ -71,8 +71,9 @@ def test_masked_gradients_ignore_padding(tensor, cfg):
 def test_kernel_path_identical(tensor, cfg):
     params = init_params(jax.random.PRNGKey(2), cfg)
     idx, val = tensor.indices[:128], tensor.values[:128]
-    g1 = ft.batch_gradients(params, idx, val, 0.01, 0.01, use_kernel=False)
-    g2 = ft.batch_gradients(params, idx, val, 0.01, 0.01, use_kernel=True)
+    g1 = ft.batch_gradients(params, idx, val, 0.01, 0.01, backend="xla")
+    g2 = ft.batch_gradients(params, idx, val, 0.01, 0.01,
+                            backend="pallas_interpret")
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)

@@ -77,9 +77,9 @@ def test_collective_accounting():
 
     out = run_with_devices("""
         import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         sh = NamedSharding(mesh, P("data"))
         def f(a):
             return jnp.sum(a)  # all-reduce of a scalar across 4 devices

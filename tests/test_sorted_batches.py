@@ -150,6 +150,24 @@ def test_segment_reduce_bitwise_vs_reference(B, J, I, bt):
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got_ref))
 
 
+@pytest.mark.parametrize(
+    "B,J,I,bt,rt", [(513, 8, 3000, 128, 64), (4096, 4, 50_000, 1024, 512),
+                    (100, 3, 10, 128, 16), (2048, 8, 700, 256, 16)])
+def test_segment_reduce_row_tiles_bitwise(B, J, I, bt, rt):
+    """Many output row tiles and batch chunks: runs that straddle chunk
+    boundaries, tiles no entry touches, and out-of-range ids all reduce
+    bitwise like segment_sum."""
+    rng = np.random.default_rng(B + I)
+    idx = rng.integers(-2, I + 3, B).astype(np.int32)
+    idx[: B // 4] = rng.integers(0, 3, B // 4)        # long duplicate runs
+    order = np.argsort(idx, kind="stable")
+    g = rng.normal(size=(B, J)).astype(np.float32)
+    want = ref.scatter_accum_ref(jnp.asarray(g), jnp.asarray(idx), I)
+    got = segment_reduce(jnp.asarray(g[order]), jnp.asarray(idx[order]), I,
+                         block_rows=rt, block_b=bt, interpret=True)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
 def test_xla_segment_reduce_bitwise_vs_scatter_accum():
     """On the xla backend the sorted scatter is bitwise == the unsorted
     one (the stable permutation preserves per-row duplicate order)."""
