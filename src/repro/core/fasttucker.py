@@ -340,12 +340,13 @@ def batch_gradients(
     phase-split flavor with cached intermediates.
     """
     backend = dispatch.resolve_backend_name(backend)
-    rows = gather_rows(params.factors, idx, layout)
-    kg = dispatch.get_backend(backend).kruskal_grad(
-        rows, params.core_factors, val,
-        mask=mask, lambda_a=lambda_a, lambda_b=lambda_b, row_mean=row_mean,
-        accum_dtype=accum_dtype,
-    )
+    with jax.named_scope("repro.step.grad"):
+        rows = gather_rows(params.factors, idx, layout)
+        kg = dispatch.get_backend(backend).kruskal_grad(
+            rows, params.core_factors, val,
+            mask=mask, lambda_a=lambda_a, lambda_b=lambda_b,
+            row_mean=row_mean, accum_dtype=accum_dtype,
+        )
     return BatchGrads(kg.row_grads, kg.core_grads, kg.err, kg.pred)
 
 
@@ -370,12 +371,14 @@ def factor_phase_gradients(
     ``core_phase_gradients`` call consumes.
     """
     backend = dispatch.resolve_backend_name(backend)
-    rows = gather_rows(params.factors, idx, layout)
-    kg = dispatch.get_backend(backend).kruskal_grad(
-        rows, params.core_factors, val,
-        mask=mask, lambda_a=lambda_a, lambda_b=lambda_b, row_mean=row_mean,
-        want_core=False, emit_c=True, accum_dtype=accum_dtype,
-    )
+    with jax.named_scope("repro.step.grad"):
+        rows = gather_rows(params.factors, idx, layout)
+        kg = dispatch.get_backend(backend).kruskal_grad(
+            rows, params.core_factors, val,
+            mask=mask, lambda_a=lambda_a, lambda_b=lambda_b,
+            row_mean=row_mean, want_core=False, emit_c=True,
+            accum_dtype=accum_dtype,
+        )
     inter = StepIntermediates(rows, kg.c, kg.pred, kg.err)
     return BatchGrads(kg.row_grads, (), kg.err, kg.pred), inter
 
@@ -402,16 +405,18 @@ def core_phase_gradients(
     uncached baseline the HLO cost test measures against).
     """
     backend = dispatch.resolve_backend_name(backend)
-    if intermediates is None:
-        rows = gather_rows(params.factors, idx, layout)
-        c = None
-    else:
-        rows, c = intermediates.rows, intermediates.c
-    kg = dispatch.get_backend(backend).kruskal_grad(
-        rows, params.core_factors, val,
-        mask=mask, lambda_a=lambda_a, lambda_b=lambda_b, row_mean=row_mean,
-        c=c, row_modes=(), want_core=True, accum_dtype=accum_dtype,
-    )
+    with jax.named_scope("repro.step.grad"):
+        if intermediates is None:
+            rows = gather_rows(params.factors, idx, layout)
+            c = None
+        else:
+            rows, c = intermediates.rows, intermediates.c
+        kg = dispatch.get_backend(backend).kruskal_grad(
+            rows, params.core_factors, val,
+            mask=mask, lambda_a=lambda_a, lambda_b=lambda_b,
+            row_mean=row_mean, c=c, row_modes=(), want_core=True,
+            accum_dtype=accum_dtype,
+        )
     return BatchGrads((), kg.core_grads, kg.err, kg.pred)
 
 
@@ -424,6 +429,13 @@ def batch_layout(
     ``sgd_step`` and all distributed strategies — threads the layout with
     one line."""
     return sorted_batch_layout(idx) if cfg.sorted_batches else None
+
+
+def _sample(key, indices, values, cfg: "FastTuckerConfig"):
+    """Ψ drawn from ``key`` and its layout (``batch_layout``)."""
+    with jax.named_scope("repro.step.sample"):
+        idx, val = sample_batch_arrays(key, indices, values, cfg.batch_size)
+        return idx, val, batch_layout(idx, cfg)
 
 
 def step_gradients(
@@ -475,10 +487,11 @@ def _scatter_mode(
     segment-reduce over the now-contiguous runs; unsorted: the
     ``scatter_accum`` fallback.
     """
-    if layout is None:
-        return bk.scatter_accum(grads, idx[:, n], num_rows)
-    return bk.segment_reduce(grads[layout.perm[n]], layout.sorted_rows[n],
-                             num_rows)
+    with jax.named_scope("repro.step.scatter"):
+        if layout is None:
+            return bk.scatter_accum(grads, idx[:, n], num_rows)
+        return bk.segment_reduce(grads[layout.perm[n]],
+                                 layout.sorted_rows[n], num_rows)
 
 
 def scatter_row_grads(
@@ -534,7 +547,8 @@ def _sgd_update(p: jax.Array, lr: jax.Array, g: jax.Array) -> jax.Array:
     no-ops); for bf16 storage the arithmetic happens in f32 and only the
     final write rounds down.
     """
-    return (p.astype(g.dtype) - lr * g).astype(p.dtype)
+    with jax.named_scope("repro.step.update"):
+        return (p.astype(g.dtype) - lr * g).astype(p.dtype)
 
 
 def _apply_updates(
@@ -607,36 +621,38 @@ def _gauss_seidel_phase_split(params, idx, val, lr_a, lr_b, cfg,
     the Pallas backends.  Bitwise identical to the joint GS step."""
     bk = dispatch.get_backend(cfg.backend)
     N = cfg.order
-    rows = list(gather_rows(params.factors, idx, layout))
-    c = [bk.mode_dot(rows[n], params.core_factors[n],
-                     accum_dtype=cfg.accum_dtype) for n in range(N)]
-    if update_factors:
-        for n in range(N):
+    # the scatters and updates inside carry their own scopes
+    with jax.named_scope("repro.step.grad"):
+        rows = list(gather_rows(params.factors, idx, layout))
+        c = [bk.mode_dot(rows[n], params.core_factors[n],
+                         accum_dtype=cfg.accum_dtype) for n in range(N)]
+        if update_factors:
+            for n in range(N):
+                kg = bk.kruskal_grad(
+                    tuple(rows), params.core_factors, val,
+                    lambda_a=cfg.lambda_a, lambda_b=cfg.lambda_b,
+                    c=tuple(c), row_modes=(n,), want_core=False,
+                    accum_dtype=cfg.accum_dtype,
+                )
+                g_n = _scatter_mode(bk, kg.row_grads[0], idx, n,
+                                    params.factors[n].shape[0], layout)
+                new_f = list(params.factors)
+                new_f[n] = _sgd_update(params.factors[n], lr_a, g_n)
+                params = FastTuckerParams(tuple(new_f), params.core_factors)
+                rows[n] = _gather_mode(params.factors[n], idx, n, layout)
+                c[n] = bk.mode_dot(rows[n], params.core_factors[n],
+                                   accum_dtype=cfg.accum_dtype)
+        if update_core:
             kg = bk.kruskal_grad(
                 tuple(rows), params.core_factors, val,
                 lambda_a=cfg.lambda_a, lambda_b=cfg.lambda_b,
-                c=tuple(c), row_modes=(n,), want_core=False,
+                c=tuple(c), row_modes=(), want_core=True,
                 accum_dtype=cfg.accum_dtype,
             )
-            g_n = _scatter_mode(bk, kg.row_grads[0], idx, n,
-                                params.factors[n].shape[0], layout)
-            new_f = list(params.factors)
-            new_f[n] = _sgd_update(params.factors[n], lr_a, g_n)
-            params = FastTuckerParams(tuple(new_f), params.core_factors)
-            rows[n] = _gather_mode(params.factors[n], idx, n, layout)
-            c[n] = bk.mode_dot(rows[n], params.core_factors[n],
-                               accum_dtype=cfg.accum_dtype)
-    if update_core:
-        kg = bk.kruskal_grad(
-            tuple(rows), params.core_factors, val,
-            lambda_a=cfg.lambda_a, lambda_b=cfg.lambda_b,
-            c=tuple(c), row_modes=(), want_core=True,
-            accum_dtype=cfg.accum_dtype,
-        )
-        core_factors = tuple(
-            _sgd_update(b, lr_b, g)
-            for b, g in zip(params.core_factors, kg.core_grads))
-        params = FastTuckerParams(params.factors, core_factors)
+            core_factors = tuple(
+                _sgd_update(b, lr_b, g)
+                for b, g in zip(params.core_factors, kg.core_grads))
+            params = FastTuckerParams(params.factors, core_factors)
     return params
 
 
@@ -658,8 +674,7 @@ def sgd_step(
     f32, structurally cheaper on the Pallas backends (and under
     gauss_seidel: 4N vs 3N(N+1) in-kernel dots).
     """
-    idx, val = sample_batch_arrays(key, indices, values, cfg.batch_size)
-    layout = batch_layout(idx, cfg)
+    idx, val, layout = _sample(key, indices, values, cfg)
     lr_a = dynamic_lr(cfg.alpha_a, cfg.beta_a, state.step)
     lr_b = dynamic_lr(cfg.alpha_b, cfg.beta_b, state.step)
 
@@ -727,8 +742,7 @@ def factor_phase_step(
     the core phase (one "step" = both phases), so ``state'.step`` is
     unchanged here and both phases share the same dynamic LR epoch.
     """
-    idx, val = sample_batch_arrays(key, indices, values, cfg.batch_size)
-    layout = batch_layout(idx, cfg)
+    idx, val, layout = _sample(key, indices, values, cfg)
     lr_a = dynamic_lr(cfg.alpha_a, cfg.beta_a, state.step)
     fg, inter = factor_phase_gradients(
         state.params, idx, val, cfg.lambda_a, cfg.lambda_b,
@@ -792,8 +806,7 @@ def _refresh_step(
 ) -> tuple[TrainState, tuple]:
     """One factor-phase step + dirty-row mask accumulation (one compile,
     reused across the K refresh steps — the window arrays keep one shape)."""
-    idx, val = sample_batch_arrays(key, indices, values, cfg.batch_size)
-    layout = batch_layout(idx, cfg)
+    idx, val, layout = _sample(key, indices, values, cfg)
     lr_a = dynamic_lr(cfg.alpha_a, cfg.beta_a, state.step)
     fg, _ = factor_phase_gradients(
         state.params, idx, val, cfg.lambda_a, cfg.lambda_b,

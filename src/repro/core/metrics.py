@@ -12,9 +12,10 @@ from .sptensor import SparseTensor
 
 @partial(jax.jit, static_argnames=("predict_fn",))
 def _chunk_err(params, idx, val, predict_fn):
-    pred = predict_fn(params, idx)
-    err = pred - val
-    return jnp.sum(err**2), jnp.sum(jnp.abs(err))
+    with jax.named_scope("repro.eval.chunk"):
+        pred = predict_fn(params, idx)
+        err = pred - val
+        return jnp.sum(err**2), jnp.sum(jnp.abs(err))
 
 
 def rmse_mae(

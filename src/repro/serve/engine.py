@@ -170,13 +170,15 @@ def _top_k_impl(tables, colsums, ids, mode, target, k, true_target_dim):
     """(scores, item ids): rank ``target``-mode entries for each ``ids`` row,
     remaining modes marginalized by their column sums (f32 scores even for
     bf16 tables — the colsums are kept f32 and the dot accumulates f32)."""
-    w = tables[mode][ids]                          # (B, R)
-    for n in range(len(tables)):
-        if n not in (mode, target):
-            w = w * colsums[n][None, :]
-    scores = jnp.matmul(w, tables[target][:true_target_dim].T,
-                        preferred_element_type=jnp.float32)  # (B, I_target)
-    values, items = jax.lax.top_k(scores, k)
+    with jax.named_scope("repro.topk.score"):
+        w = tables[mode][ids]                      # (B, R)
+        for n in range(len(tables)):
+            if n not in (mode, target):
+                w = w * colsums[n][None, :]
+        scores = jnp.matmul(w, tables[target][:true_target_dim].T,
+                            preferred_element_type=jnp.float32)
+    with jax.named_scope("repro.topk.select"):
+        values, items = jax.lax.top_k(scores, k)
     return values, items
 
 
@@ -618,32 +620,39 @@ class TuckerServer:
 
         Requests are bucketed/padded (answers are invariant to batch size)
         and chunked above the largest bucket — the jit cache never exceeds
-        ``len(self.ladder)`` entries per backend.
+        ``len(self.ladder)`` entries per backend.  The host work, from
+        the checks to the last trimmed answer, is the profiler span
+        ``repro.serve.dispatch`` (stat ``buckets``: chunks launched).
         """
-        # pad on the HOST (numpy memcpy) so each bucket costs exactly one
-        # device transfer + one executable launch — the per-request Python
-        # overhead is what the ≥10× batched-vs-per-query margin lives on
-        indices = np.asarray(indices, np.int32)
-        if indices.ndim != 2 or indices.shape[1] != self.order:
-            raise ValueError(
-                f"indices must be (B, {self.order}), got {indices.shape}")
-        B = indices.shape[0]
-        # host-side range check: the sharded and unsharded gathers disagree
-        # on out-of-range rows (zero-mask vs clamp), so reject them here
-        # rather than return mode-dependent wrong answers
-        if B and ((indices < 0).any()
-                  or (indices >= np.asarray(self.dims)).any()):
-            raise ValueError(f"indices out of range for dims {self.dims}")
-        if B == 0:
-            # match the nonempty path: predictions are f32 accum results
-            # even when the tables are stored bf16
-            return jnp.zeros((0,), jnp.float32)
-        live = self._live         # one snapshot: all chunks, one generation
-        outs = []
-        for padded, n in self._bucketed_chunks(indices):
-            pred = self._predict_fn(live.tables, self._eyes, padded)
-            outs.append(pred if n == padded.shape[0] else pred[:n])
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+        with jax.profiler.TraceAnnotation("repro.serve.dispatch") as span:
+            # pad on the HOST (numpy memcpy) so each bucket costs exactly
+            # one device transfer + one executable launch — the per-request
+            # Python overhead is what the ≥10× batched-vs-per-query margin
+            # lives on
+            indices = np.asarray(indices, np.int32)
+            if indices.ndim != 2 or indices.shape[1] != self.order:
+                raise ValueError(
+                    f"indices must be (B, {self.order}), "
+                    f"got {indices.shape}")
+            B = indices.shape[0]
+            # host-side range check: the sharded and unsharded gathers
+            # disagree on out-of-range rows (zero-mask vs clamp), so reject
+            # them here rather than return mode-dependent wrong answers
+            if B and ((indices < 0).any()
+                      or (indices >= np.asarray(self.dims)).any()):
+                raise ValueError(
+                    f"indices out of range for dims {self.dims}")
+            if B == 0:
+                # match the nonempty path: predictions are f32 accum
+                # results even when the tables are stored bf16
+                return jnp.zeros((0,), jnp.float32)
+            live = self._live     # one snapshot: all chunks, one generation
+            outs = []
+            for padded, n in self._bucketed_chunks(indices):
+                pred = self._predict_fn(live.tables, self._eyes, padded)
+                outs.append(pred if n == padded.shape[0] else pred[:n])
+            span.set_metadata(buckets=len(outs))
+            return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
     def reconstruct_rows(self, mode: int, ids) -> jax.Array:
         """Factored reconstruction of whole mode-``mode`` slices.
@@ -671,30 +680,34 @@ class TuckerServer:
         ``k`` highest-scoring entries of ``target_mode`` (default: the next
         mode), remaining modes marginalized (summed) via cached column sums.
 
-        Returns (scores (B, k), item ids (B, k)).
+        Returns (scores (B, k), item ids (B, k)).  The host work is the
+        profiler span ``repro.serve.dispatch``, as in ``predict``.
         """
-        mode = self._check_mode(mode)
-        target = ((mode + 1) % self.order if target_mode is None
-                  else self._check_mode(target_mode))
-        if target == mode:
-            raise ValueError(f"target_mode must differ from mode {mode}")
-        if not 1 <= k <= self.dims[target]:
-            raise ValueError(f"k={k} outside 1..{self.dims[target]}")
-        ids = self._check_ids(ids, mode)
-        if len(ids) == 0:
-            return (jnp.zeros((0, k), jnp.float32),
-                    jnp.zeros((0, k), jnp.int32))
-        live = self._live         # one snapshot: all chunks, one generation
-        scores, items = [], []
-        for chunk, n in self._bucketed_chunks(ids):
-            s, i = self._top_k_fn(live.tables, live.colsums, chunk,
-                                  mode=mode, target=target, k=k,
-                                  true_target_dim=self.dims[target])
-            scores.append(s[:n])
-            items.append(i[:n])
-        if len(scores) == 1:
-            return scores[0], items[0]
-        return jnp.concatenate(scores), jnp.concatenate(items)
+        with jax.profiler.TraceAnnotation("repro.serve.dispatch") as span:
+            mode = self._check_mode(mode)
+            target = ((mode + 1) % self.order if target_mode is None
+                      else self._check_mode(target_mode))
+            if target == mode:
+                raise ValueError(
+                    f"target_mode must differ from mode {mode}")
+            if not 1 <= k <= self.dims[target]:
+                raise ValueError(f"k={k} outside 1..{self.dims[target]}")
+            ids = self._check_ids(ids, mode)
+            if len(ids) == 0:
+                return (jnp.zeros((0, k), jnp.float32),
+                        jnp.zeros((0, k), jnp.int32))
+            live = self._live     # one snapshot: all chunks, one generation
+            scores, items = [], []
+            for chunk, n in self._bucketed_chunks(ids):
+                s, i = self._top_k_fn(live.tables, live.colsums, chunk,
+                                      mode=mode, target=target, k=k,
+                                      true_target_dim=self.dims[target])
+                scores.append(s[:n])
+                items.append(i[:n])
+            span.set_metadata(buckets=len(scores))
+            if len(scores) == 1:
+                return scores[0], items[0]
+            return jnp.concatenate(scores), jnp.concatenate(items)
 
     # -- online refresh (delta patch + versioned swap) ------------------------
 

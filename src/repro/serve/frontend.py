@@ -24,16 +24,27 @@ The engine call itself runs on a single worker thread
 (``loop.run_in_executor``): jax dispatch is blocking, the device
 serializes batches anyway, and one thread keeps the event loop free to
 keep admitting/shedding while a batch is in flight.
+
+Each flush records profiler spans, inert unless a ``jax.profiler`` trace
+is being captured: ``repro.serve.flush`` on the event loop (stats
+``flush_id``, ``requests``, ``queries``, ``wait_sum_us``, ``wait_max_us``:
+the queue wait of the flush's live requests) and, on the worker thread,
+``repro.serve.engine`` (``flush_id``, ``queries``) around the engine
+call, with ``repro.serve.wait`` (the device) and ``repro.serve.fetch``
+(device to host) inside it.
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
+import itertools
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Sequence
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .bucketing import bucket_for
 
@@ -93,18 +104,15 @@ class FrontendStats:
     shed_queue_full: int = 0     # rejected at submit (bounded queue)
     shed_deadline: int = 0       # dropped at flush (deadline passed)
     flushes: int = 0             # engine calls issued
-    table_version: int = 0       # server table version the last flush ran on
     stale_flushes: int = 0       # flushes answered by a version that a
                                  # table swap superseded while in flight
     degraded_flushes: int = 0    # flushes served while the refresh
                                  # supervisor reported state=degraded
-    latency_ms: list = dataclasses.field(default_factory=list)
     by_bucket: dict = dataclasses.field(default_factory=dict)
     slo_violations: dict = dataclasses.field(default_factory=dict)
 
     def record(self, bucket: int, ms: float,
                slo_ms: float | None = None) -> None:
-        self.latency_ms.append(ms)
         self.by_bucket.setdefault(bucket, []).append(ms)
         if slo_ms is not None:
             # zero-init on first sighting so the report distinguishes
@@ -114,9 +122,9 @@ class FrontendStats:
                 self.slo_violations[bucket] += 1
 
     def percentiles(self, qs: Sequence[float] = (50, 95, 99)) -> dict:
-        if not self.latency_ms:
+        if not self.by_bucket:
             return {f"p{q:g}": None for q in qs}
-        lat = np.asarray(self.latency_ms)
+        lat = np.concatenate([np.asarray(v) for v in self.by_bucket.values()])
         return {f"p{q:g}": float(np.percentile(lat, q)) for q in qs}
 
     def bucket_percentiles(self, qs: Sequence[float] = (50, 95, 99)) -> dict:
@@ -182,6 +190,7 @@ class ServeFrontend:
         self._task: asyncio.Task | None = None
         self._executor: ThreadPoolExecutor | None = None
         self._closing = False
+        self._flush_ids = itertools.count()
 
     # -- lifecycle ------------------------------------------------------------
 
@@ -273,6 +282,15 @@ class ServeFrontend:
                 return
 
     async def _flush(self) -> None:
+        flush_id = next(self._flush_ids)
+        with TraceAnnotation("repro.serve.flush", flush_id=flush_id) as span:
+            live = self._sweep(span)
+            if live:
+                await self._answer(live, flush_id)
+
+    def _sweep(self, span: TraceAnnotation) -> list[_Pending]:
+        """Take the queue, shed what is past its deadline, and return the
+        rest; their queue waits go on ``span``."""
         now = self._clock()
         deadline = self.admission.deadline_ms / 1e3
         batch, self._queue = self._queue, []
@@ -286,20 +304,27 @@ class ServeFrontend:
                     f"{(now - p.enqueued) * 1e3:.1f}ms in queue"))
             else:
                 live.append(p)
-        if not live:
-            return
+        waits = [now - p.enqueued for p in live]
+        span.set_metadata(
+            requests=len(live),
+            queries=sum(p.indices.shape[0] for p in live),
+            wait_sum_us=1e6 * sum(waits),
+            wait_max_us=1e6 * max(waits, default=0.0))
+        return live
+
+    async def _answer(self, live: list[_Pending], flush_id: int) -> None:
+        """One engine call for ``live``; resolve each request's future."""
         indices = np.concatenate([p.indices for p in live])
         loop = asyncio.get_running_loop()
         version = getattr(self.server, "table_version", 0)
         try:
             results = await loop.run_in_executor(
-                self._executor, self._serve_batch, indices)
+                self._executor, self._serve_batch, indices, flush_id)
         except Exception as e:   # surface engine errors to every waiter
             for p in live:
                 p.future.set_exception(e)
             return
         self.stats.flushes += 1
-        self.stats.table_version = version
         if getattr(self.server, "table_version", 0) != version:
             # an online table swap landed while this flush was in flight:
             # its answers are consistent (one version end to end) but stale
@@ -326,17 +351,21 @@ class ServeFrontend:
             self.stats.record(bucket, (done - p.enqueued) * 1e3,
                               slo_ms=self.admission.slo_for(bucket))
 
-    def _serve_batch(self, indices: np.ndarray):
-        import jax
-        if self.query == "predict":
-            return np.asarray(
-                jax.block_until_ready(self.server.predict(indices)))
-        mode, k, *rest = self.top_k_args
-        target = rest[0] if rest else None
-        scores, items = self.server.top_k(mode, indices, k,
-                                          target_mode=target)
-        jax.block_until_ready(scores)
-        return np.asarray(scores), np.asarray(items)
+    def _serve_batch(self, indices: np.ndarray, flush_id: int):
+        with TraceAnnotation("repro.serve.engine", flush_id=flush_id,
+                             queries=len(indices)):
+            if self.query == "predict":
+                out = self.server.predict(indices)
+            else:
+                mode, k, *rest = self.top_k_args
+                target = rest[0] if rest else None
+                out = self.server.top_k(mode, indices, k, target_mode=target)
+            with TraceAnnotation("repro.serve.wait"):
+                jax.block_until_ready(out)
+            with TraceAnnotation("repro.serve.fetch"):
+                if self.query == "predict":
+                    return np.asarray(out)
+                return tuple(np.asarray(r) for r in out)
 
 
 # ---------------------------------------------------------------------------
