@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import FastTuckerConfig, init_state
 from repro.core import fasttucker as ft
-from repro.core.metrics import _chunk_err
+from repro.core.metrics import _held_out_err
 from repro.core.sptensor import SparseTensor
 from repro.distributed import get_strategy
 from repro.serve import AdmissionConfig, ServeFrontend, TuckerServer
@@ -64,9 +64,16 @@ def test_evaluation_chunk_carries_its_scope():
     cfg = _cfg()
     params = ft.init_params(jax.random.PRNGKey(0), cfg)
     t = _tensor()
-    text = _lowered_text(_chunk_err.lower(params, t.indices, t.values,
-                                          predict_fn=ft.predict))
+    text = _lowered_text(_held_out_err.lower(params, t.indices, t.values,
+                                             predict_fn=ft.predict, chunk=16))
     assert _has_scope(text, "repro.eval.chunk")
+    # the pack is named inside it, and no op of the evaluation sits under
+    # an inner repro.* scope: the evaluation's share counts all of it
+    assert _has_scope(text, "lane_pack")
+    paths = re.findall(r'loc\("([^"]*repro\.[^"]*)"', text)
+    assert paths
+    assert all(re.findall(r"repro\.[A-Za-z0-9_.]+", p)[-1]
+               == "repro.eval.chunk" for p in paths), paths
 
 
 def test_top_k_carries_score_and_select_scopes():
