@@ -64,8 +64,20 @@ def _by_name(kind: str, name: str, suffix: str) -> Path:
     return path
 
 
+def check_config(name: str, cfg: dict) -> dict:
+    """``cfg`` if its ``ranks`` give one entry per mode of ``dims``; a
+    configuration that does not is refused, naming it: the program takes
+    the order from ``dims`` and the reference from ``ranks``."""
+    dims, ranks = cfg["dims"], cfg["ranks"]
+    if len(ranks) != len(dims):
+        raise ValueError(
+            f"configuration {name!r}: ranks has {len(ranks)} entries for "
+            f"the {len(dims)} modes of dims")
+    return cfg
+
+
 def load_config(name: str) -> dict:
-    return load_json(_by_name("configs", name, ".json"))
+    return check_config(name, load_json(_by_name("configs", name, ".json")))
 
 
 def load_mix(name: str) -> dict:
@@ -295,7 +307,8 @@ def context(bench: dict, workload: str, seed: int, seconds: float,
     """The cell's files (or ``cfg``/``mix`` in their place), its chips,
     and the compile listener."""
     wl = workload_entry(bench, workload)
-    cfg = load_config(wl["config"]) if cfg is None else cfg
+    cfg = (load_config(wl["config"]) if cfg is None
+           else check_config(cfg.get("name", wl["config"]), cfg))
     mix = load_mix(wl["traffic"]) if mix is None else mix
     listen_compiles()
     return Context(workload, cfg, mix, int(seed), float(seconds),

@@ -10,29 +10,21 @@ run at a tiny size on the CPU, with the timed path broken underneath:
   flush); half of each flush answered wrongly; and the control, the
   reference rounded to float8 put in the program's place.
 
-A single chip has no exchange between chips to leave out.  The sound
-run of each cell reads correct at the same size (the rehearsal).
-``yahoo.train`` runs the code of ``netflix.train`` on other sizes.
+The faults are listed by the kind and query of a cell's mix
+(``tiny.FAULTS``), and every cell of the benchmark, with the rehearsal's
+own cells, runs its kind's list at its own shrunk configuration and
+against its own limits.  A single chip has no exchange between chips to
+leave out.  The sound run of each cell reads correct at the same size
+(the rehearsal).
 """
 from __future__ import annotations
 
 import pytest
 
-from chipbench import harness
-
 from . import tiny
 
-CASES = [
-    ("netflix.train", {"fault": "unchanged"}),
-    ("netflix.train", {"fault": "half_batch"}),
-    ("netflix.train", {"variant": {"dtype": "bfloat16"}}),
-    ("yahoo.serve_topk", {"fault": "altered"}),
-    ("yahoo.serve_topk", {"fault": "half_batch"}),
-    ("yahoo.serve_topk", {"check_dtype": "float8_e4m3fn"}),
-    ("netflix.serve_predict", {"fault": "altered"}),
-    ("netflix.serve_predict", {"fault": "half_batch"}),
-    ("netflix.serve_predict", {"check_dtype": "float8_e4m3fn"}),
-]
+CELLS = [w["name"] for w in tiny.bench()["workloads"]]
+CASES = [(w, o) for w in CELLS for o in tiny.FAULTS.get(tiny.kind_of(w), [])]
 
 
 @pytest.mark.parametrize("workload,options", CASES,
@@ -41,11 +33,9 @@ CASES = [
 def test_fault_or_control_reads_not_correct(workload, options):
     out = tiny.run(workload, **options)
     assert out["correct"] is False
-    over = [name for name, c in out["checks"].items()
-            if c["limit"] is not None and not c["value"] <= c["limit"]]
-    assert over, out["checks"]
+    assert tiny.over_limits(out), out["checks"]
 
 
 def test_every_cell_has_a_fault_case():
-    cells = {w["name"] for w in harness.load_benchmark()["workloads"]}
-    assert cells <= {w for w, _ in CASES} | {"yahoo.train"}
+    for w in CELLS:
+        assert tiny.kind_of(w) in tiny.FAULTS, w

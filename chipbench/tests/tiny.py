@@ -33,6 +33,18 @@ BURST_ARRIVALS = {"process": "onoff", "rate_per_s": 2800, "on_s": 0.5,
                   "off_s": 1.5}
 EXTRA = {PREDICT["name"]: PREDICT, BURST["name"]: BURST}
 
+# the faults a cell can have and its control, by the kind and query of
+# its mix: every cell, one added as files alone too, runs its kind's
+# list at its own shrunk configuration (``test_bench_faults.py``)
+FAULTS = {
+    ("train", None): [{"fault": "unchanged"}, {"fault": "half_batch"},
+                      {"variant": {"dtype": "bfloat16"}}],
+    ("serve", "top_k"): [{"fault": "altered"}, {"fault": "half_batch"},
+                         {"check_dtype": "float8_e4m3fn"}],
+    ("serve", "predict"): [{"fault": "altered"}, {"fault": "half_batch"},
+                           {"check_dtype": "float8_e4m3fn"}],
+}
+
 
 def bench() -> dict:
     b = copy.deepcopy(harness.load_benchmark())
@@ -56,13 +68,28 @@ def mix_of(workload: str) -> dict:
         "traffic"])
 
 
-def shrink(workload: str) -> tuple[dict, dict]:
-    """The cell's configuration and mix, cut to a tiny tensor."""
-    wl = harness.workload_entry(bench(), workload)
-    cfg = harness.load_config(wl["config"])
+def kind_of(workload: str) -> tuple[str, str | None]:
+    """The kind and query of the cell's mix, which key ``FAULTS``."""
     mix = mix_of(workload)
-    cfg.update(dims=[300, 200, 40], train_nnz=40_000, test_nnz=3_000,
-               batch=256, rmse_target=0.5)
+    return mix["kind"], mix.get("query")
+
+
+def over_limits(out: dict) -> list[str]:
+    """The compared numbers of a run that exceed their limits."""
+    return [name for name, c in out["checks"].items()
+            if c["limit"] is not None and not c["value"] <= c["limit"]]
+
+
+def shrink(workload: str, cfg: dict | None = None) -> tuple[dict, dict]:
+    """The cell's configuration (or ``cfg`` in its place) and mix, cut to
+    a tiny tensor of the same order; ranks and core rank stay as given."""
+    wl = harness.workload_entry(bench(), workload)
+    cfg = (harness.load_config(wl["config"]) if cfg is None
+           else copy.deepcopy(cfg))
+    mix = mix_of(workload)
+    order = len(cfg["dims"])
+    cfg.update(dims=[300, 200, 40] if order == 3 else [40] * order,
+               train_nnz=40_000, test_nnz=3_000, batch=256, rmse_target=0.5)
     mix.update(trace_seconds=0.2)
     if mix["kind"] == "serve":
         mix["arrivals"]["rate_per_s"] = 50
@@ -73,8 +100,8 @@ def shrink(workload: str) -> tuple[dict, dict]:
 
 
 def run(workload: str, *, trace: bool = False, seconds: float = 0.4,
-        seed: int = SEED, **options) -> dict:
-    cfg, mix = shrink(workload)
+        seed: int = SEED, cfg: dict | None = None, **options) -> dict:
+    cfg, mix = shrink(workload, cfg)
     if workload == PREDICT["name"]:
         options.setdefault("limits", PREDICT_LIMITS)
     if workload == BURST["name"]:
