@@ -49,11 +49,16 @@ def mode_dots(
          for r, b in zip(rows, core_factors)], axis=0)
 
 
+@jax.named_scope("exclusive_products")
 def exclusive_products(c: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Given c: (N, B, R), return (full_prod (B,R), excl (N,B,R)).
 
     excl[n] = Π_{k≠n} c[k], computed division-free with prefix/suffix
     products (stable when some c ≈ 0).
+
+    Its ops carry the scope ``exclusive_products`` inside the caller's
+    (``repro.step.grad``, ``repro.eval.chunk``); the name has no
+    ``repro.`` prefix, so the caller's scope still owns the device time.
     """
     N = c.shape[0]
     ones = jnp.ones_like(c[0])

@@ -1,8 +1,11 @@
 """Synthetic HOHDST generators (paper Tables 4 & 5 analogues).
 
 ``planted_tensor`` draws ground-truth Tucker factors and emits noisy
-observations at uniformly random indices — used for convergence/accuracy
-benchmarks (the RMSE floor is the noise level).
+observations at distinct uniformly random indices — used for
+convergence/accuracy benchmarks (the RMSE floor is the noise level).  A
+tensor whose flat index fits in int64 draws flat indices; a larger one
+(the paper's synthesis tensors from order 5 up) draws each mode's
+coordinate on its own.
 
 ``ratings_tensor`` mimics the real recommender datasets: values in
 [min_value, max_value], heavy-tailed mode sizes.
@@ -15,10 +18,44 @@ import jax.numpy as jnp
 from repro.core.sptensor import SparseTensor
 
 
+_MIX = np.uint64(0x9E3779B97F4A7C15)
+
+
+def _row_keys(idx):
+    """A 64-bit hash of each index tuple: equal tuples, equal keys."""
+    keys = np.zeros(len(idx), dtype=np.uint64)
+    for n in range(idx.shape[1]):
+        keys = (keys ^ idx[:, n].astype(np.uint64)) * _MIX
+        keys ^= keys >> np.uint64(29)
+    return keys
+
+
+def _unique_rows_per_mode(rng, dims, nnz):
+    """nnz distinct index tuples, each mode's coordinate drawn on its own.
+
+    For tensors whose flat index overflows int64.  A row is kept only if
+    its key (``_row_keys``) is new, so the rows kept are distinct; a
+    duplicate (or a key collision) is dropped and the shortfall redrawn.
+    """
+    idx = np.zeros((0, len(dims)), dtype=np.int32)
+    keys = np.zeros(0, dtype=np.uint64)
+    while len(idx) < nnz:
+        extra = np.stack([rng.integers(0, d, size=nnz - len(idx))
+                          for d in dims], axis=1).astype(np.int32)
+        keys = np.concatenate([keys, _row_keys(extra)])
+        idx = np.concatenate([idx, extra])
+        _, first = np.unique(keys, return_index=True)
+        first.sort()
+        idx, keys = idx[first], keys[first]
+    return idx
+
+
 def _unique_indices(rng, dims, nnz):
     """nnz distinct random index tuples (rejection-free for sparse regime)."""
     dims = np.asarray(dims, dtype=np.int64)
     total = np.prod(dims.astype(object))
+    if total > 2 ** 63:
+        return _unique_rows_per_mode(rng, dims, nnz)
     flat = rng.integers(0, int(total), size=int(nnz * 1.2), dtype=np.int64)
     flat = np.unique(flat)[:nnz]
     while len(flat) < nnz:

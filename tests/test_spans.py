@@ -24,16 +24,21 @@ STEP_SCOPES = {"repro.step.sample", "repro.step.grad", "repro.step.scatter",
                "repro.step.update"}
 
 
-def _cfg(**kw):
-    return FastTuckerConfig(dims=DIMS, ranks=(3, 4, 2), core_rank=3,
+# an order-10 tensor, as the paper's synthesis set goes to
+DIMS10 = (6, 5, 4, 7, 3, 5, 4, 6, 3, 5)
+
+
+def _cfg(dims=DIMS, **kw):
+    ranks = (3, 4, 2) if dims == DIMS else (3,) * len(dims)
+    return FastTuckerConfig(dims=dims, ranks=ranks, core_rank=3,
                             batch_size=16, **kw)
 
 
-def _tensor(nnz=60, seed=0):
+def _tensor(nnz=60, seed=0, dims=DIMS):
     rng = np.random.default_rng(seed)
-    idx = np.stack([rng.integers(0, d, nnz) for d in DIMS], 1)
+    idx = np.stack([rng.integers(0, d, nnz) for d in dims], 1)
     return SparseTensor(jax.numpy.asarray(idx, np.int32),
-                        jax.numpy.asarray(rng.random(nnz), np.float32), DIMS)
+                        jax.numpy.asarray(rng.random(nnz), np.float32), dims)
 
 
 def _lowered_text(lowered) -> str:
@@ -74,6 +79,42 @@ def test_evaluation_chunk_carries_its_scope():
     assert paths
     assert all(re.findall(r"repro\.[A-Za-z0-9_.]+", p)[-1]
                == "repro.eval.chunk" for p in paths), paths
+
+
+def _op_paths(compiled) -> list[str]:
+    """The scope path of every op of a compiled program (its ``op_name``
+    metadata, which a TPU trace shows as the op's ``tf_op``)."""
+    return re.findall(r'op_name="([^"]*)"', compiled.as_text())
+
+
+def _innermost_repro(path: str) -> str:
+    return re.findall(r"repro\.[A-Za-z0-9_.]+", path)[-1]
+
+
+@pytest.mark.parametrize("dims", [DIMS, DIMS10], ids=["order3", "order10"])
+def test_exclusive_products_are_named_inside_step_and_evaluation(dims):
+    """The N-way exclusive products carry their own unprefixed name in
+    the step and in the evaluation, under the enclosing ``repro.*`` scope,
+    which keeps their device time."""
+    cfg = _cfg(dims)
+    t = _tensor(dims=dims)
+    st = get_strategy("local")
+    plan = st.prepare(t, cfg, None)
+    key = jax.random.PRNGKey(0)
+    ds = st.init(plan, init_state(key, cfg), key)
+    step = st.lower_step(plan, ds).compile()
+    evaluation = _held_out_err.lower(
+        ft.init_params(key, cfg), t.indices, t.values,
+        predict_fn=ft.predict, chunk=16).compile()
+    for compiled, outer in ((step, "repro.step.grad"),
+                            (evaluation, "repro.eval.chunk")):
+        paths = [p for p in _op_paths(compiled)
+                 if "exclusive_products" in p.split("/")]
+        assert paths, outer
+        assert any(p.endswith("/rev") for p in paths), paths
+        for p in paths:
+            assert f"{outer}/" in p.split("exclusive_products")[0], p
+            assert _innermost_repro(p) == outer, p
 
 
 def test_top_k_carries_score_and_select_scopes():
